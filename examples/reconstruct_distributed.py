@@ -6,6 +6,10 @@ reduce-scatter — then demonstrates checkpoint/restart by killing the job
 mid-stream and resuming.
 
     PYTHONPATH=src python examples/reconstruct_distributed.py
+
+It needs 8 devices: on the CPU the XLA_FLAGS line below makes 8 virtual
+ones. On a TPU host with fewer chips (a 4-chip v5e host) it does not run;
+`chip_smoke.py --chips 4` is the multi-chip check there.
 """
 import os
 

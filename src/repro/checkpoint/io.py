@@ -42,9 +42,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.compat import (
-    tree_flatten, tree_flatten_with_path, tree_map, tree_unflatten,
-)
 from repro.io import shard_store
 from repro.io.shard_store import StoreError
 
@@ -101,7 +98,7 @@ def save_checkpoint(directory: str, step: int, tree: PyTree) -> str:
         shutil.rmtree(tmp)
     leaves_dir = os.path.join(tmp, "leaves")
     os.makedirs(leaves_dir, exist_ok=True)
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     manifest = {"step": step, "format": "shard-store-v1", "leaves": []}
     for idx, (keypath, leaf) in enumerate(flat):
         name = f"leaf_{idx:05d}"
@@ -168,7 +165,7 @@ def load_checkpoint(directory: str, step: int, like: PyTree,
     except (json.JSONDecodeError, OSError) as e:
         raise StoreError(f"unreadable checkpoint manifest {mpath!r}: {e}"
                          ) from e
-    flat, treedef = tree_flatten(like)
+    flat, treedef = jax.tree.flatten(like)
     if len(flat) != len(manifest["leaves"]):
         raise ValueError(
             f"checkpoint has {len(manifest['leaves'])} leaves, expected {len(flat)}"
@@ -186,7 +183,7 @@ def load_checkpoint(directory: str, step: int, like: PyTree,
             out.append(shard_store.load_array(leaf_dir, sharding))
         else:
             out.append(jax.device_put(shard_store.load_array(leaf_dir)))
-    return tree_unflatten(treedef, out)
+    return jax.tree.unflatten(treedef, out)
 
 
 class CheckpointManager:
@@ -203,7 +200,7 @@ class CheckpointManager:
         # Snapshot shard-by-shard to host memory synchronously (cheap, and
         # keeps each shard's global index + the leaf's PartitionSpec for
         # the per-shard files), write async.
-        host_tree = tree_map(shard_store.snapshot, tree)
+        host_tree = jax.tree.map(shard_store.snapshot, tree)
         self.wait()
 
         def _write():

@@ -99,20 +99,43 @@ def _filter_batch(proj: Array, fcos: Array, hf: Array, pad: int, tau_u: float,
     return (q * tau_u).astype(out_dtype or proj.dtype)
 
 
+# Projections one FFT step filters. The padded planes and the spectrum of
+# one projection take ~20x its f32 bytes on XLA:TPU (47 MB at a 768^2
+# detector), so a whole scan filtered in one step would hold tens of GB;
+# chunks of this size bound that to under 1 GB whatever the batch.
+FILTER_CHUNK = 16
+
+
+def _chunk(n: int) -> int:
+    """The largest divisor of n that is <= FILTER_CHUNK (no padding copy)."""
+    return max(c for c in range(1, min(n, FILTER_CHUNK) + 1) if n % c == 0)
+
+
 def make_filter(g: CBCTGeometry, window: str = "ramlak", out_dtype=None):
     """Returns filter_fn(proj: (B, N_v, N_u)) -> (B, N_v, N_u), plus tables.
 
     `out_dtype` is the *storage* dtype of the emitted filtered projections
     (the precision policy's half-width stream, see core/precision.py); the
     FFT convolution itself always runs in f32. None keeps the input dtype.
+    Batches larger than FILTER_CHUNK are filtered chunk by chunk in a
+    sequential loop; every detector row sees the same FFT either way.
     """
     pad = fft_length(g.n_u)
     fcos = jnp.asarray(cosine_weights(g))
     hf = jnp.asarray(ramp_frequency_response(g, window, pad))
     out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else None
 
-    def filter_fn(proj: Array) -> Array:
+    def filter_batch(proj: Array) -> Array:
         return _filter_batch(proj, fcos, hf, pad, g.tau_u, out_dtype)
+
+    def filter_fn(proj: Array) -> Array:
+        n = proj.shape[0]
+        c = _chunk(n)
+        if c == n:
+            return filter_batch(proj)
+        chunks = proj.reshape((n // c, c) + proj.shape[1:])
+        out = jax.lax.map(filter_batch, chunks)
+        return out.reshape((n,) + out.shape[2:])
 
     return filter_fn
 

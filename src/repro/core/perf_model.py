@@ -130,6 +130,29 @@ TPU_V5E = MachineSpec(
 )
 
 
+# The machine a TPU is priced as, keyed by `device_kind` as JAX reports it
+# (a v5e reports "TPU v5 lite"). One table: a kind missing here is an
+# error, never a silent default.
+TPU_MACHINES = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def machine_for(device) -> MachineSpec:
+    """The MachineSpec the planner prices `device` with: the TPU table for
+    TPUs (unknown kinds raise), the paper's ABCI constants elsewhere (the
+    CPU backend has no constants of its own)."""
+    if device.platform != "tpu":
+        return ABCI
+    try:
+        return TPU_MACHINES[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no MachineSpec for TPU kind {device.device_kind!r}; add it to "
+            f"core/perf_model.TPU_MACHINES (known: {sorted(TPU_MACHINES)})"
+        ) from None
+
+
 @dataclasses.dataclass(frozen=True)
 class PerfBreakdown:
     t_load: float

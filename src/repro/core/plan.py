@@ -44,7 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.obs.trace import get_tracer
 from repro.parallel.mesh import AXIS_DATA, AXIS_MODEL, AXIS_POD, axis_size
 from .cache import CountingLRU
@@ -66,6 +66,8 @@ _SCHEDULES = ("fused", "pipelined", "chunked", "incremental")
 _REDUCES = ("psum",) + SCATTER_REDUCES
 _IMPLS = ("reference", "factorized", "kernel")
 _PRECISIONS = ("fp32", "bf16", "fp16", "fp8_e4m3", "fp8_e5m2")
+# Wire dtypes the compiled kernel cannot load on TPU (v5e: no f16 vectors).
+_KERNEL_REFUSED_ON_TPU = ("fp16",)
 
 # build()/build_batched() results, keyed by the (hashable) plan (plus batch
 # size for batched engines): repeated builds of the same plan reuse the
@@ -289,6 +291,14 @@ class ReconstructionPlan:
             raise ValueError(
                 f"impl='kernel' requires even N_z (dual-slab layout), "
                 f"got N_z={g.n_z}")
+        if self.impl == "kernel" and self.resolved_precision().storage in \
+                _KERNEL_REFUSED_ON_TPU:
+            from repro.planner.feasibility import plan_device
+            if plan_device(self.mesh).platform == "tpu":
+                raise ValueError(
+                    f"impl='kernel' cannot read {self.resolved_precision().storage} "
+                    "streams on TPU: Mosaic has no vector load for that dtype "
+                    "(tests/test_chip_compile.py); use bf16 or another impl")
         if self.blocks is not None:
             bi, bj, bs = self.blocks
             nx_call, ny_call, _ = self._bp_call_shape()
@@ -301,6 +311,12 @@ class ReconstructionPlan:
                     f"blocks=(bi={bi}, bj={bj}) must tile the per-call "
                     f"back-projection slab ({nx_call}, {ny_call}) — the "
                     f"x-slab/y-chunk of one gathered micro-batch")
+            from repro.kernels.backproject.kernel import tile_is_legal
+            if not tile_is_legal(bj, ny_call):
+                raise ValueError(
+                    f"blocks=(bj={bj}) must be a multiple of 8 or the whole "
+                    f"per-call N_y={ny_call} (the kernel's (8, 128) output "
+                    "tiling)")
         return self
 
     # -- kernel block resolution (plan-time, not per-call) ------------------
@@ -332,7 +348,8 @@ class ReconstructionPlan:
             return _get_backprojector(self.impl)
         from repro.kernels.backproject.ops import backproject_pallas
         bi, bj, bs = self.resolved_blocks()
-        return partial(backproject_pallas, bi=bi, bj=bj, bs=bs)
+        return partial(backproject_pallas, bi=bi, bj=bj, bs=bs,
+                       vmem_budget=self.vmem_budget)
 
     def _span_attrs(self) -> dict:
         """Fixed span args of this plan's engines (trace labels) — built

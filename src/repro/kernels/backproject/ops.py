@@ -15,14 +15,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.backprojection import _stream_scales, from_dual_slab
-from .kernel import backproject_dual_pallas
+from .kernel import backproject_dual_pallas, resolve_interpret, vmem_bytes
 from . import tune
 
 Array = jax.Array
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def backproject_pallas(pmats: Array, proj: Array,
@@ -44,11 +40,14 @@ def backproject_pallas(pmats: Array, proj: Array,
     Block shapes not given explicitly come from the VMEM-budget autotuner
     (tune.pick_blocks): candidates that tile the problem, pruned against
     `vmem_budget` (default REPRO_BP_VMEM_BUDGET), model-ranked — or timed
-    once per (geometry, dtype) when REPRO_BP_AUTOTUNE=time.
+    once per (geometry, dtype) when REPRO_BP_AUTOTUNE=time. The kernel's
+    scoped-VMEM limit is that budget (or the tile's working set, if larger).
+
+    `interpret` runs the Pallas interpreter; None defers to
+    REPRO_PALLAS_INTERPRET (kernel.resolve_interpret).
     """
     n_p = proj.shape[0]
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     qt = jnp.swapaxes(proj, -1, -2)  # (Np, Nu, Nv): v contiguous
     nu, nv = qt.shape[1], qt.shape[2]
     if bi is None or bj is None or bs is None:
@@ -65,9 +64,11 @@ def backproject_pallas(pmats: Array, proj: Array,
         pad = bs - n_p % bs
         qt = jnp.pad(qt, ((0, pad), (0, 0), (0, 0)))
         pm = jnp.pad(pm, ((0, pad), (0, 0)), constant_values=1.0)
+    budget = tune.DEFAULT_VMEM_BUDGET if vmem_budget is None else vmem_budget
+    limit = max(budget, vmem_bytes(bi, bj, bs, nu, nv, nz // 2, qt.dtype))
     dual = backproject_dual_pallas(
-        pm, qt, nx, ny, nz, bi=bi, bj=bj, bs=bs, interpret=interpret
-    )
+        pm, qt, nx, ny, nz, bi=bi, bj=bj, bs=bs, interpret=interpret,
+        vmem_limit=limit)
     return from_dual_slab(dual)
 
 

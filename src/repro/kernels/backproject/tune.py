@@ -5,19 +5,21 @@ shape determines both the VMEM working set (kernel.vmem_bytes) and the HBM
 traffic — the projection batch is re-streamed once per (gi, gj) output tile,
 so total Q^T traffic is (nx/bi)*(ny/bj) * Np*Nu*Nv*itemsize. The tuner
 
-  1. enumerates candidates that tile the problem (bi | nx, bj | ny, bs a
-     power of two — ops.py pads the projection axis),
+  1. enumerates candidates that tile the problem (bi | nx, bj | ny with bj
+     a multiple of 8 or ny itself, bs a power of two — ops.py pads the
+     projection axis),
   2. prunes them against a configurable VMEM budget with the kernel's own
-     vmem_bytes() model (storage dtype aware: bf16/fp16 projections double
-     the feasible batch),
+     vmem_bytes() model (double-buffered blocks plus temporaries; storage
+     dtype aware: bf16/fp16 projections halve the projection blocks),
   3. ranks the survivors by the traffic model, and — in measured mode —
      times the few best with the real kernel once per (geometry, dtype),
      memoized in an in-process cache.
 
 Knobs:
-  REPRO_BP_VMEM_BUDGET   VMEM budget in bytes (default 8 MiB — half of a
-                         TPU core's ~16 MiB, leaving room for double
-                         buffering and spills).
+  REPRO_BP_VMEM_BUDGET   VMEM budget in bytes (default 32 MiB). A v5e core
+                         has 128 MiB of VMEM but Mosaic's default scoped
+                         limit is 16 MiB, so the kernel raises its limit to
+                         this budget (kernels/backproject/ops.py).
   REPRO_BP_AUTOTUNE      "time" to measure survivors on every first use of
                          a geometry (default: model-ranked pick, no timing
                          — interpret-mode timing is python-speed).
@@ -43,10 +45,13 @@ import numpy as np
 
 from repro.filecache import JsonFileCache
 
-from .kernel import backproject_dual_pallas, vmem_bytes
+from .kernel import (
+    backproject_dual_pallas, resolve_interpret, tile_is_legal, vmem_bytes,
+)
 
-DEFAULT_VMEM_BUDGET = int(os.environ.get("REPRO_BP_VMEM_BUDGET", 8 * 2**20))
-_BLOCK_CAP = 64  # largest tile edge / projection batch considered
+DEFAULT_VMEM_BUDGET = int(os.environ.get("REPRO_BP_VMEM_BUDGET", 32 * 2**20))
+_BLOCK_CAP = 64  # largest bi / projection batch considered
+_BJ_CAP = 1024   # largest column block (the MXU matmul's row count)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,7 +131,8 @@ def candidate_blocks(nx: int, ny: int, n_p: int, nu: int, nv: int, nzh: int,
     """
     budget = DEFAULT_VMEM_BUDGET if budget is None else budget
     bis = [fix_bi] if fix_bi else _divisors(nx, _BLOCK_CAP)
-    bjs = [fix_bj] if fix_bj else _divisors(ny, _BLOCK_CAP)
+    bjs = ([fix_bj] if fix_bj else
+           [d for d in _divisors(ny, _BJ_CAP) if tile_is_legal(d, ny)])
     bss = [fix_bs] if fix_bs else _pow2_leq(n_p, _BLOCK_CAP)
     out = []
     for bi in bis:
@@ -153,24 +159,25 @@ def min_vmem_bytes(nx: int, ny: int, n_p: int, nu: int, nv: int, nzh: int,
 
 def _traffic_score(c: BlockConfig, n_p: int) -> tuple:
     """Rank key, larger = better: minimize Q^T re-streaming (maximize the
-    output tile), then minimize padded projection work (ops.py zero-pads
-    n_p up to a bs multiple — wasted back-projection per tile), then
-    amortize per-batch overhead (maximize bs)."""
+    output tile), then fill the MXU (maximize bj, the matmul's rows), then
+    minimize padded projection work (ops.py zero-pads n_p up to a bs
+    multiple — wasted back-projection per tile), then amortize per-batch
+    overhead (maximize bs)."""
     padded = -(-n_p // c.bs) * c.bs
-    return (c.bi * c.bj, -padded, c.bs, -c.vmem)
+    return (c.bi * c.bj, c.bj, -padded, c.bs, -c.vmem)
 
 
 def _time_candidate(c: BlockConfig, nx: int, ny: int, nz: int, n_p: int,
                     nu: int, nv: int, qt_dtype, interpret: bool,
-                    iters: int) -> float:
+                    iters: int, budget: int) -> float:
     n_pad = -(-n_p // c.bs) * c.bs  # padding overhead is part of the cost
     pm = np.zeros((n_pad, 12), np.float32)
     pm[:, 11] = 1.0  # z == 1: no division hazard on synthetic data
     pm = jnp.asarray(pm)
     qt = jnp.zeros((n_pad, nu, nv), qt_dtype)
     run = lambda: backproject_dual_pallas(  # noqa: E731
-        pm, qt, nx, ny, nz, bi=c.bi, bj=c.bj, bs=c.bs, interpret=interpret
-    )
+        pm, qt, nx, ny, nz, bi=c.bi, bj=c.bj, bs=c.bs, interpret=interpret,
+        vmem_limit=max(budget, c.vmem))
     jax.block_until_ready(run())  # compile / warm up
     t0 = time.perf_counter()
     for _ in range(iters):
@@ -200,8 +207,7 @@ def autotune(nx: int, ny: int, nz: int, n_p: int, nu: int, nv: int,
     if nz % 2:
         raise ValueError("back-projection kernel requires even N_z")
     budget = DEFAULT_VMEM_BUDGET if budget is None else budget
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     qt_dtype = jnp.dtype(qt_dtype)
     # The key is the tuning *problem*, not the tuning mode: a measured
     # winner (elapsed > 0) satisfies both measured and model-ranked
@@ -252,7 +258,8 @@ def autotune(nx: int, ny: int, nz: int, n_p: int, nu: int, nv: int,
         timed = [
             dataclasses.replace(
                 c, elapsed=_time_candidate(c, nx, ny, nz, n_p, nu, nv,
-                                           qt_dtype, interpret, iters)
+                                           qt_dtype, interpret, iters,
+                                           budget)
             )
             for c in ranked[:max_measure]
         ]

@@ -6,7 +6,9 @@ Axis conventions (DESIGN.md §4):
   model : tensor/expert parallelism   (ICI). iFDK: volume slabs (paper R).
 
 `make_mesh` is a thin wrapper so importing this module never touches device
-state; meshes are always built explicitly by launchers.
+state; meshes are always built explicitly by launchers. Every axis is
+`Auto`: the pipeline places data with shard_map and sharding constraints,
+not with the `Explicit` sharding types `jax.make_mesh` defaults to.
 """
 from __future__ import annotations
 
@@ -14,7 +16,7 @@ from typing import Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AXIS_POD = "pod"
 AXIS_DATA = "data"
@@ -28,7 +30,8 @@ def dp_axes(mesh: Mesh) -> Tuple[str, ...]:
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices=None) -> Mesh:
     if devices is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+        return jax.make_mesh(tuple(shape), tuple(axes),
+                             axis_types=(AxisType.Auto,) * len(axes))
     devs = np.asarray(devices).reshape(tuple(shape))
     return Mesh(devs, tuple(axes))
 

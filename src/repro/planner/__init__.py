@@ -21,7 +21,7 @@ from .calibrate import CalibrationStore, MachineCalibration, \
 from .cost import IMPL_GUPS_FACTOR, PlanPoint, point_from_plan, \
     predict_plan, predict_point
 from .feasibility import DEFAULT_HBM_BYTES, MemoryFootprint, \
-    check_feasible, plan_footprint
+    check_feasible, hbm_bytes_for, plan_device, plan_footprint
 from .measure import measure_proposal, refine
 from .search import PlanProposal, admitted_impls, auto_plan, \
     enumerate_points, search_grids, search_plans
@@ -32,7 +32,8 @@ __all__ = [
     "set_default_store",
     "IMPL_GUPS_FACTOR", "PlanPoint", "point_from_plan", "predict_plan",
     "predict_point", "DEFAULT_HBM_BYTES", "MemoryFootprint",
-    "check_feasible", "plan_footprint", "measure_proposal", "refine",
+    "check_feasible", "hbm_bytes_for", "plan_device", "plan_footprint",
+    "measure_proposal", "refine",
     "PlanProposal", "admitted_impls", "auto_plan", "enumerate_points",
     "search_grids", "search_plans",
 ]

@@ -49,8 +49,24 @@ from repro.core.precision import resolve_precision
 
 from .cost import PlanPoint
 
-# Default per-device HBM budget: 16 GiB (v5e chip / paper's V100).
+# Per-device HBM budget off TPU: 16 GiB (the paper's V100).
 DEFAULT_HBM_BYTES = 16 * 2**30
+
+
+def plan_device(mesh=None):
+    """The device a plan on `mesh` (None = the default device) runs on —
+    what the planner prices and budgets."""
+    import jax
+
+    return jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+
+
+def hbm_bytes_for(device) -> int:
+    """The HBM budget of `device`: what the TPU runtime says it may
+    allocate (`memory_stats()["bytes_limit"]`), DEFAULT_HBM_BYTES off TPU."""
+    if device.platform != "tpu":
+        return DEFAULT_HBM_BYTES
+    return int(device.memory_stats()["bytes_limit"])
 
 
 @dataclasses.dataclass(frozen=True)

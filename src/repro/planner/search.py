@@ -36,13 +36,13 @@ from typing import Iterable, Optional, Sequence
 from repro.core.distributed import IFDKGrid, SCATTER_REDUCES, grid_candidates
 from repro.core.geometry import CBCTGeometry
 from repro.core.perf_model import (
-    ABCI, MachineSpec, PerfBreakdown, gups_end_to_end,
+    ABCI, MachineSpec, PerfBreakdown, gups_end_to_end, machine_for,
 )
 from repro.core.precision import resolve_precision
 
 from .cost import PlanPoint, predict_point
 from .feasibility import DEFAULT_HBM_BYTES, MemoryFootprint, check_feasible, \
-    plan_footprint
+    hbm_bytes_for, plan_device, plan_footprint
 
 _SCHEDULE_ORDER = ("fused", "pipelined", "chunked")
 # Ranking knows every schedule, including the pin-only streaming one.
@@ -190,9 +190,17 @@ def search_grids(g: CBCTGeometry, n_devices: int, *,
     return proposals[:top_k]
 
 
+def _device_defaults(mesh, system, hbm_bytes):
+    """`system`/`hbm_bytes`, or what the device a plan on `mesh` runs on
+    says: its MachineSpec (core/perf_model.machine_for) and HBM limit."""
+    device = plan_device(mesh)
+    return (machine_for(device) if system is None else system,
+            hbm_bytes_for(device) if hbm_bytes is None else hbm_bytes)
+
+
 def search_plans(g: CBCTGeometry, mesh=None, *,
-                 system: MachineSpec = ABCI,
-                 hbm_bytes: int = DEFAULT_HBM_BYTES,
+                 system: MachineSpec | None = None,
+                 hbm_bytes: int | None = None,
                  vmem_budget: int | None = None,
                  top_k: int | None = 8, include_infeasible: bool = False,
                  window: str = "ramlak", calibration=None,
@@ -202,9 +210,13 @@ def search_plans(g: CBCTGeometry, mesh=None, *,
     Every proposal's `plan` is a `ReconstructionPlan` that has passed
     `validate()`; candidates validate() rejects (scatter without a data
     axis, chunk extents that do not divide over it, ...) are dropped.
+    `system`/`hbm_bytes` default to the mesh's device: its MachineSpec
+    (core/perf_model.machine_for) and its HBM limit (hbm_bytes_for).
     """
     from repro.core.plan import ReconstructionPlan
     from repro.parallel.mesh import AXIS_DATA, axis_size
+
+    system, hbm_bytes = _device_defaults(mesh, system, hbm_bytes)
 
     if mesh is None or AXIS_DATA not in mesh.axis_names:
         enumerate_kwargs.setdefault("reduces", ("psum",))
@@ -258,8 +270,8 @@ def admitted_impls(calibration=None) -> tuple[str, ...]:
 
 
 def auto_plan(g: CBCTGeometry, mesh=None, *,
-              system: MachineSpec = ABCI,
-              hbm_bytes: int = DEFAULT_HBM_BYTES,
+              system: MachineSpec | None = None,
+              hbm_bytes: int | None = None,
               vmem_budget: int | None = None,
               measure: bool = False, top_k: int = 8,
               window: str = "ramlak", calibration="auto", **pins):
@@ -275,12 +287,14 @@ def auto_plan(g: CBCTGeometry, mesh=None, *,
       a MachineSpec        — caller-supplied constants, no overlay;
       None                 — stock constants, calibration off.
 
+    `system`/`hbm_bytes` default to the mesh's device (see search_plans).
     `pins` fix search dimensions the caller chose (e.g. precision="bf16"
     restricts the precision axis; n_steps=4 the micro-batching). Raises
     ValueError when no candidate is both valid and feasible.
     """
     from .calibrate import resolve_calibration
 
+    system, hbm_bytes = _device_defaults(mesh, system, hbm_bytes)
     cal, system = resolve_calibration(calibration, system)
 
     kw = {}

@@ -104,7 +104,8 @@ class ReconstructionService:
                    search, once per family — see PlanCache).
     max_batch    : bucket-size ceiling (power of two recommended).
     max_queue    : admission bound on queued scans (QueueFullError beyond).
-    hbm_bytes    : per-device memory budget for admission + bucket sizing.
+    hbm_bytes    : per-device memory budget for admission + bucket sizing
+                   (None = the device's HBM limit, planner.hbm_bytes_for).
     policy       : cross-family bucket scheduling order (SCHEDULING_POLICIES).
     """
 
@@ -113,7 +114,7 @@ class ReconstructionService:
                  vmem_budget: Optional[int] = None,
                  plan_cache_capacity: int = 32, prefetch_depth: int = 2,
                  writeback_depth: int = 2, policy: str = "fifo"):
-        from repro.planner import DEFAULT_HBM_BYTES
+        from repro.planner import hbm_bytes_for, plan_device
         if max_batch < 1:
             raise ValueError(f"max_batch={max_batch} must be >= 1")
         if policy not in SCHEDULING_POLICIES:
@@ -122,7 +123,8 @@ class ReconstructionService:
         self.mesh = mesh
         self.max_batch = int(max_batch)
         self.max_queue = int(max_queue)
-        self.hbm_bytes = DEFAULT_HBM_BYTES if hbm_bytes is None else hbm_bytes
+        self.hbm_bytes = (hbm_bytes_for(plan_device(mesh))
+                          if hbm_bytes is None else hbm_bytes)
         self.vmem_budget = vmem_budget
         self.prefetch_depth = prefetch_depth
         self.policy = policy
@@ -422,6 +424,10 @@ class ReconstructionService:
                                 jnp.float32)
                 lanes.extend([pad] * n_pad)
             batch = jnp.stack(lanes)
+            # The stacked batch is now the only copy the bucket needs; on
+            # a device the lanes would otherwise stay resident beside it
+            # (B scans of projections) for the whole dispatch.
+            lanes.clear()
             if self.mesh is not None:
                 batch = jax.device_put(
                     batch, batched_input_sharding(self.mesh))
@@ -431,6 +437,7 @@ class ReconstructionService:
             for t in tickets:
                 t._set_state(TicketState.SERVING)
             out = engine(batch)
+            del batch
             bucket_span.fence(out)
             layout = None
             if (plan.schedule == "chunked"

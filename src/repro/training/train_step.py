@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import tree_map
 from repro.models.config import ModelConfig
 from repro.models.transformer import (
     abstract_params, init_params, loss_fn, param_shardings,
@@ -43,8 +42,8 @@ def make_abstract_state(cfg: ModelConfig) -> TrainState:
         params=params,
         opt=OptState(
             step=jax.ShapeDtypeStruct((), jnp.int32),
-            mu=tree_map(f32, params),
-            nu=tree_map(f32, params),
+            mu=jax.tree.map(f32, params),
+            nu=jax.tree.map(f32, params),
         ),
     )
 
@@ -81,22 +80,22 @@ def make_train_step(cfg: ModelConfig,
             def slice_mb(x):
                 b = x.shape[0]
                 return x.reshape(microbatches, b // microbatches, *x.shape[1:])
-            mbs = tree_map(slice_mb, batch)
+            mbs = jax.tree.map(slice_mb, batch)
 
             def mb_step(acc, mb):
                 loss_acc, grad_acc = acc
                 loss, grads = grads_of(params, mb)
-                grad_acc = tree_map(jnp.add, grad_acc, grads)
+                grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
                 return (loss_acc + loss, grad_acc), None
 
-            zero_g = tree_map(
+            zero_g = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
             (loss, grads), _ = lax.scan(
                 mb_step, (jnp.zeros((), jnp.float32), zero_g), mbs
             )
             loss = loss / microbatches
-            grads = tree_map(lambda g: g / microbatches, grads)
+            grads = jax.tree.map(lambda g: g / microbatches, grads)
 
         lr_scale = cosine_schedule(state.opt.step + 1, warmup, total_steps)
         new_params, new_opt, om = adamw_update(

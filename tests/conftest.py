@@ -4,6 +4,8 @@ import tempfile
 import jax
 import pytest
 
+from repro.compile_cache import enable_compile_cache
+
 # NOTE: no XLA_FLAGS here — smoke tests must see the real (1-device) CPU.
 # Distributed tests spawn subprocesses that set
 # --xla_force_host_platform_device_count themselves.
@@ -22,6 +24,9 @@ os.environ.setdefault(
     os.path.join(tempfile.mkdtemp(prefix="repro-plan-"),
                  "plan_measure_cache.json"),
 )
+# The CPU suite runs the Pallas kernels in the interpreter; the program
+# never picks interpret mode by itself (kernels/backproject/kernel.py).
+os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 # The calibration store stays OFF by default in tests: traced runs and
 # measured refinements would otherwise accumulate host-specific timings
 # into ~/.cache and make auto_plan's "auto" calibration nondeterministic
@@ -34,17 +39,11 @@ jax.config.update("jax_enable_x64", False)
 # The fast tier is compile-bound (hundreds of small jitted engines), not
 # compute-bound: XLA's persistent compilation cache cuts repeat runs on the
 # same machine by roughly a third. Keyed by HLO, so it can never change
-# results — only skip recompiles. REPRO_COMPILE_CACHE=off disables it;
-# any other value overrides the cache directory.
-_cc = os.environ.get("REPRO_COMPILE_CACHE", "")
-if _cc.lower() not in ("off", "0"):
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        _cc or os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            "xla_cache"))
-    # Only persist compiles that cost real time — writing every trivial
-    # executable to disk costs more on the cold run than it saves warm.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+# results — only skip recompiles. Same rule as every entry point:
+# JAX_COMPILATION_CACHE_DIR if set, else .jax_cache/ in the checkout. Only
+# compiles that cost real time are persisted — writing every trivial
+# executable to disk costs more on the cold run than it saves warm.
+enable_compile_cache(min_compile_secs=0.2)
 
 
 def pytest_configure(config):

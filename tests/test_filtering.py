@@ -95,3 +95,22 @@ class TestFiltering:
         assert np.all(w <= 1.0 + 1e-6) and np.all(w > 0)
         cu, cv = (g.n_u - 1) // 2, (g.n_v - 1) // 2
         assert w[cv, cu] == w.max()
+
+
+class TestChunkedFilter:
+    """Batches above FILTER_CHUNK are filtered chunk by chunk: the FFT's
+    working set is bounded, the result is the one-shot filter's."""
+
+    @pytest.mark.parametrize("n_proj", [20, 32, 17])
+    def test_chunked_equals_per_chunk(self, n_proj):
+        from repro.core import filtering
+        from repro.core.phantom import forward_project
+        g = default_geometry(8, n_proj=n_proj)
+        proj = forward_project(g)
+        filt = make_filter(g)
+        got = np.asarray(filt(proj))
+        c = filtering._chunk(n_proj)
+        assert n_proj % c == 0 and c <= filtering.FILTER_CHUNK
+        want = np.concatenate([np.asarray(filt(proj[i:i + c]))
+                               for i in range(0, n_proj, c)])
+        np.testing.assert_array_equal(got, want)

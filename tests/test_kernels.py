@@ -8,9 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.backprojection import backproject_factorized, to_dual_slab
+from repro.core.backprojection import (
+    backproject_factorized, backproject_reference, to_dual_slab,
+)
 from repro.core.filtering import filter_projections
-from repro.core.geometry import default_geometry, projection_matrices
+from repro.core.geometry import (
+    CBCTGeometry, default_geometry, projection_matrices,
+)
 from repro.core.phantom import forward_project
 from repro.kernels.backproject.kernel import backproject_dual_pallas, vmem_bytes
 from repro.kernels.backproject.ops import backproject_mxu, backproject_pallas
@@ -36,7 +40,8 @@ class TestPallasKernel:
         np.testing.assert_allclose(np.array(got), np.array(want),
                                    rtol=1e-5, atol=1e-6)
 
-    @pytest.mark.parametrize("bi,bj,bs", [(4, 4, 2), (8, 8, 4), (16, 16, 12)])
+    # bj is a multiple of 8 or the whole N_y: the (8, 128) output tiling
+    @pytest.mark.parametrize("bi,bj,bs", [(4, 8, 2), (8, 8, 4), (16, 16, 12)])
     def test_block_shape_sweep(self, bi, bj, bs):
         g, pm, q = _case(16, 12)
         want = backproject_factorized(pm, q, g.n_x, g.n_y, g.n_z)
@@ -62,9 +67,25 @@ class TestPallasKernel:
         np.testing.assert_allclose(np.array(got), np.array(want),
                                    rtol=1e-5, atol=1e-6)
 
+    # Detectors narrower than the volume's shadow: taps fall off both edges
+    # of the detector line (v in (-1, 0) and past N_v - 1).
+    @pytest.mark.parametrize("n_det,half", [(12, 1.6), (20, 1.3)])
+    def test_detector_edges_vs_reference(self, n_det, half):
+        g = CBCTGeometry(n_proj=8, n_u=n_det, n_v=n_det,
+                         d_u=2 * half / n_det, d_v=2 * half / n_det,
+                         d=4.0, dsd=8.0, n_x=16, n_y=16, n_z=16,
+                         d_x=2 / 16, d_y=2 / 16, d_z=2 / 16)
+        pm = jnp.asarray(projection_matrices(g))
+        q = filter_projections(g, forward_project(g))
+        want = backproject_reference(pm, q, g.n_x, g.n_y, g.n_z)
+        got = backproject_pallas(pm, q, g.n_x, g.n_y, g.n_z)
+        np.testing.assert_allclose(np.array(got), np.array(want),
+                                   rtol=1e-5, atol=1e-6)
+
     def test_vmem_budget_helper(self):
-        # a VMEM-conscious config for a 1k detector (bf16 batch of 2) fits
-        assert vmem_bytes(8, 8, 2, 1024, 1024, 512, jnp.bfloat16) < 8 * 2**20
+        # a VMEM-conscious config for a 1k detector (bf16 batch of 2, both
+        # double-buffered) fits the default 32 MiB budget
+        assert vmem_bytes(8, 8, 2, 1024, 1024, 512, jnp.bfloat16) < 32 * 2**20
         # and the helper scales linearly in the batch block
         assert vmem_bytes(8, 8, 4, 64, 64, 32) > vmem_bytes(8, 8, 2, 64, 64, 32)
 

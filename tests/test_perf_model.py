@@ -4,7 +4,8 @@ import pytest
 from repro.core.distributed import IFDKGrid
 from repro.core.geometry import paper_geometry as paper_problem
 from repro.core.perf_model import (
-    ABCI, TPU_V5E, MachineSpec, SystemConstants, gups_end_to_end, predict,
+    ABCI, TPU_V5E, MachineSpec, SystemConstants, gups_end_to_end,
+    machine_for, predict,
 )
 
 
@@ -183,3 +184,23 @@ class TestPinnedPaperProjection:
         assert b.t_runtime == pytest.approx(15.33, rel=0.01)
         assert b.t_runtime < 30.0  # the paper's headline claim
         assert gups_end_to_end(g, b) == pytest.approx(17100, rel=0.01)
+
+
+class TestMachineFor:
+    """The planner prices a TPU from its device_kind, never a default."""
+
+    @staticmethod
+    def _dev(platform, kind):
+        import types
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    def test_v5e_kind_maps_to_v5e(self):
+        assert machine_for(self._dev("tpu", "TPU v5 lite")) is TPU_V5E
+
+    def test_unknown_tpu_kind_raises(self):
+        with pytest.raises(ValueError, match="no MachineSpec for TPU kind"):
+            machine_for(self._dev("tpu", "TPU v99"))
+
+    def test_cpu_keeps_paper_constants(self):
+        import jax
+        assert machine_for(jax.devices()[0]) is ABCI

@@ -210,8 +210,8 @@ class TestPlanResolution:
         bi, bj, bs = tuned.resolved_blocks()
         assert g.n_x % bi == 0 and g.n_y % bj == 0
         pinned = ReconstructionPlan(geometry=g, impl="kernel",
-                                    blocks=(4, 4, 4))
-        assert pinned.resolved_blocks() == (4, 4, 4)
+                                    blocks=(4, 8, 4))
+        assert pinned.resolved_blocks() == (4, 8, 4)
         out = np.asarray(pinned.build()(proj))
         _assert_matches_oracle(out, oracle, "fp32", "kernel/pinned-blocks")
 
@@ -313,6 +313,25 @@ class TestValidate:
         g = dataclasses.replace(default_geometry(16, n_proj=8), n_z=15)
         with pytest.raises(ValueError, match="even N_z"):
             self._plan(g=g, impl="kernel").validate()
+
+    @pytest.mark.parametrize("platform,refused", [("tpu", True),
+                                                  ("cpu", False)])
+    def test_kernel_fp16_refused_on_tpu(self, monkeypatch, platform,
+                                        refused):
+        """Mosaic cannot load f16 vectors on v5e: the kernel with fp16
+        streams is refused on TPU, not silently compiled elsewhere."""
+        import types
+        from repro.planner import feasibility
+        monkeypatch.setattr(feasibility, "plan_device",
+                            lambda mesh=None: types.SimpleNamespace(
+                                platform=platform))
+        plan = self._plan(impl="kernel", precision="fp16")
+        if refused:
+            with pytest.raises(ValueError, match="cannot read fp16"):
+                plan.validate()
+        else:
+            plan.validate()
+        self._plan(impl="kernel", precision="bf16").validate()
 
 
 class TestChooseGrid:

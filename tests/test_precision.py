@@ -424,8 +424,11 @@ class TestAutotuner:
         oracle — the tuner only changes the tiling, never the math."""
         g, proj, pm, oracle = case16
         q = filter_projections(g, proj, out_dtype=jnp.float32)
+        # the tightest budget any tiling fits: forces the minimal tile
+        budget = tune.min_vmem_bytes(g.n_x, g.n_y, g.n_proj, g.n_u, g.n_v,
+                                     g.n_z // 2)
         out = backproject_pallas(pm, q, g.n_x, g.n_y, g.n_z,
-                                 vmem_budget=64 * 1024)
+                                 vmem_budget=budget)
         scale = float(jnp.max(jnp.abs(oracle))) + 1e-12
         assert float(jnp.max(jnp.abs(out - oracle))) / scale < 1e-4
 
@@ -433,7 +436,7 @@ class TestAutotuner:
         g, proj, pm, oracle = case16
         q = filter_projections(g, proj, out_dtype=jnp.float32)
         out = backproject_pallas(pm, q, g.n_x, g.n_y, g.n_z,
-                                 bi=4, bj=4, bs=4)
+                                 bi=4, bj=8, bs=4)
         scale = float(jnp.max(jnp.abs(oracle))) + 1e-12
         assert float(jnp.max(jnp.abs(out - oracle))) / scale < 1e-4
 
