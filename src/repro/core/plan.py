@@ -25,7 +25,9 @@ stage primitives:
 into one rank function, run under shard_map when a mesh is given and
 directly on one device when not. The schedule x reduce x precision x impl
 cross-product is fully available — including combinations the legacy
-builders never offered (chunked+psum, pipelined single-device).
+builders never offered (chunked+psum, pipelined single-device). Each
+primitive runs under a `jax.named_scope` (`STAGE_SCOPES`), so every device
+operation of every schedule names its stage in its `op_name` metadata.
 
 Tuned Pallas block shapes for `impl="kernel"` are resolved ONCE at plan
 time (kernels/backproject/tune.py, file-backed cache) instead of per-call
@@ -99,6 +101,21 @@ def _traced_call(fn: Callable, name: str, attrs: dict) -> Callable:
         return out
     call.__wrapped__ = fn
     return call
+
+
+# The engine's stage scopes (`jax.named_scope`): metadata only, they reach
+# the compiled module's `op_name` and change no instruction. A device trace
+# attributes an operation to a stage by the scope in its op_name.
+STAGE_SCOPES = ("fdk.filter", "fdk.encode", "fdk.gather", "fdk.backproject",
+                "fdk.reduce")
+
+
+def _scoped(scope: str, fn: Callable) -> Callable:
+    """`fn` traced under the stage scope `scope`."""
+    def run(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+    return run
 
 
 def engine_cache_stats() -> dict:
@@ -411,7 +428,9 @@ class ReconstructionPlan:
         # The filter emits f32; the stream codec owns the quantization to
         # the wire format (scale-free codecs are a plain cast — fused under
         # jit, byte-identical to casting inside the filter).
-        filt = make_filter(g, self.window, out_dtype=jnp.float32)
+        filt = _scoped("fdk.filter",
+                       make_filter(g, self.window, out_dtype=jnp.float32))
+        encode = _scoped("fdk.encode", codec.encode)
 
         # --- stage: filter + encode + column AllGather (paper Fig. 3b) -----
         # The AllGather moves the codec's WIRE format: quantized data plus,
@@ -421,8 +440,9 @@ class ReconstructionPlan:
         # the FFT must not see a vmap batch dim, see build_batched), while
         # `gather_cols` moves the wire bytes over the model axis.
         def filter_encode(raw_b: Array):
-            return codec.encode(filt(raw_b))
+            return encode(filt(raw_b))
 
+        @partial(_scoped, "fdk.gather")
         def gather_cols(pm_b: Array, data: Array, scales):
             if model_axis is None:
                 return pm_b, data, scales
@@ -437,6 +457,7 @@ class ReconstructionPlan:
             return gather_cols(pm_b, *filter_encode(raw_b))
 
         # --- stage: x-slab reparameterization (offset folded into P) -------
+        @partial(_scoped, "fdk.backproject")
         def slab_pmats(pm_col: Array) -> Array:
             if model_axis is None:
                 return pm_col
@@ -449,6 +470,7 @@ class ReconstructionPlan:
         # <= C_data * eps_bf16/2 on the reduced slab); the cross-pod finish
         # stays f32. Plain "scatter"/"psum" paths are byte-identical to the
         # f32 collective (the astype(f32) is a no-op on an f32 slab).
+        @partial(_scoped, "fdk.reduce")
         def reduce_slab(slab: Array) -> Array:
             if not dp:
                 return slab
@@ -468,7 +490,8 @@ class ReconstructionPlan:
             gather_batch=gather_batch, filter_encode=filter_encode,
             gather_cols=gather_cols, slab_pmats=slab_pmats,
             reduce_slab=reduce_slab,
-            backproject=self._resolve_backprojector(),
+            backproject=_scoped("fdk.backproject",
+                                self._resolve_backprojector()),
             nx_slab=nx_slab, scale=fdk_scale(g),
             model_axis=model_axis, data_axis=data_axis, pod_axis=pod_axis,
             dp=dp,
@@ -568,6 +591,7 @@ class ReconstructionPlan:
         scatter = self.reduce in SCATTER_REDUCES
         compensated = self.reduce == "scatter_bf16"
         yc_local = yc // self._data_size if scatter else yc
+        shift_j = _scoped("fdk.backproject", shift_pmats_j)
 
         def chunk_reduce(part: Array) -> Array:
             if scatter:
@@ -587,26 +611,28 @@ class ReconstructionPlan:
 
                 def one_chunk(ci, st):
                     a, e = st
-                    pm_c = shift_pmats_j(pm_slab,
-                                         (ci * yc).astype(pm_slab.dtype))
+                    pm_c = shift_j(pm_slab, (ci * yc).astype(pm_slab.dtype))
                     part = backproject(pm_c, q_col, nx_slab, yc, g.n_z,
                                        scales=sc_col)
-                    if compensated:
-                        # error feedback: re-inject the residual this rank
-                        # dropped when it quantized the SAME chunk last
-                        # round, so quantization error does not accumulate
-                        # over the n_steps micro-batches — only the final
-                        # round's rounding survives (one per rank).
-                        part = part + lax.dynamic_index_in_dim(
-                            e, ci, axis=1, keepdims=False)
-                        half = part.astype(jnp.bfloat16)
-                        e = lax.dynamic_update_index_in_dim(
-                            e, part - half.astype(jnp.float32), ci, axis=1)
-                        red = lax.psum_scatter(
-                            half, data_axis, scatter_dimension=1,
-                            tiled=True).astype(jnp.float32)
-                    else:
-                        red = chunk_reduce(part)
+                    with jax.named_scope("fdk.reduce"):
+                        if compensated:
+                            # error feedback: re-inject the residual this
+                            # rank dropped when it quantized the SAME chunk
+                            # last round, so quantization error does not
+                            # accumulate over the n_steps micro-batches —
+                            # only the final round's rounding survives (one
+                            # per rank).
+                            part = part + lax.dynamic_index_in_dim(
+                                e, ci, axis=1, keepdims=False)
+                            half = part.astype(jnp.bfloat16)
+                            e = lax.dynamic_update_index_in_dim(
+                                e, part - half.astype(jnp.float32), ci,
+                                axis=1)
+                            red = lax.psum_scatter(
+                                half, data_axis, scatter_dimension=1,
+                                tiled=True).astype(jnp.float32)
+                        else:
+                            red = chunk_reduce(part)
                     a = lax.dynamic_update_index_in_dim(
                         a, a[:, ci] + red, ci, axis=1)
                     return a, e
@@ -628,7 +654,8 @@ class ReconstructionPlan:
                 (pm_steps[1:],) + tuple(x[1:] for x in steps))
             acc, _ = bp_chunks((acc, err), *last)  # epilogue
             if pod_axis is not None:
-                acc = lax.psum(acc, pod_axis)
+                with jax.named_scope("fdk.reduce"):
+                    acc = lax.psum(acc, pod_axis)
             if not scatter:
                 # dims 1,2 are contiguous locally when nothing is scattered
                 acc = acc.reshape(nx_slab, g.n_y, g.n_z)
@@ -1189,6 +1216,7 @@ class IncrementalSession:
             return acc_slab + backproject(pm_s, q_col, nx_slab, g.n_y,
                                           g.n_z, scales=sc_col)
 
+        @partial(_scoped, "fdk.reduce")
         def fin_slab(acc_new):
             """Per-rank finalize of the NEW accumulator block (epilogue of
             the fused last-delta dispatch) — mirrors _get_finalize_fn."""
@@ -1201,6 +1229,7 @@ class IncrementalSession:
                     slab = lax.psum(slab, a)
             return slab * scale
 
+        @partial(_scoped, "fdk.reduce")
         def accumulate(acc, carry, part):
             if compensated:
                 # error feedback along the time axis: re-inject the
@@ -1498,7 +1527,8 @@ class IncrementalSession:
                 return slab * scale
 
         self._finalize_fn = jax.jit(shard_map(
-            rank, mesh=mesh, in_specs=(self._acc_spec,),
+            _scoped("fdk.reduce", rank), mesh=mesh,
+            in_specs=(self._acc_spec,),
             out_specs=output_spec(mesh, plan.reduce), check_vma=False))
         return self._finalize_fn
 

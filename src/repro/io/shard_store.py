@@ -419,6 +419,36 @@ def read_region(path: str, index: Sequence[slice] | Index,
     return out
 
 
+def read_shards(path: str, sharding) -> HostShardedArray:
+    """The regions `sharding` places on this host's devices, read from the
+    store into host memory: each distinct region once (replicas share it),
+    opening only the shard files that intersect it. The host half of a
+    scatter read; `put_shards` is the device half."""
+    m = read_manifest(path)
+    shape = tuple(m["shape"])
+    regions: dict = {}
+    for idx in sharding.addressable_devices_indices_map(shape).values():
+        key = _normalize_index(idx, shape) if idx else ()
+        if key not in regions:
+            regions[key] = np.ascontiguousarray(
+                read_region(path, key, manifest=m))
+    return HostShardedArray(shape=shape, dtype=dtype_from_name(m["dtype"]),
+                            spec=None, shards=list(regions.items()))
+
+
+def put_shards(host: HostShardedArray, sharding) -> jax.Array:
+    """`device_put` each device's region of `host` (from `read_shards`
+    with the same `sharding`) and join the pieces into the global array."""
+    regions = dict(host.shards)
+    pieces = [
+        jax.device_put(
+            regions[_normalize_index(idx, host.shape) if idx else ()], dev)
+        for dev, idx in
+        sharding.addressable_devices_indices_map(host.shape).items()]
+    return jax.make_array_from_single_device_arrays(host.shape, sharding,
+                                                    pieces)
+
+
 def load_array(path: str, sharding=None) -> Any:
     """Restore a stored array.
 
@@ -427,22 +457,14 @@ def load_array(path: str, sharding=None) -> Any:
                           sharding places on this host, open only the
                           intersecting shard files and build the global
                           jax.Array — the target mesh need not match the
-                          writer's (reshard-on-restore).
+                          writer's (reshard-on-restore). Every region is
+                          read before any piece goes to its device.
     """
-    m = read_manifest(path)
-    shape = tuple(m["shape"])
     if sharding is None:
-        return read_region(path, tuple((0, d) for d in shape), manifest=m)
-    imap = sharding.addressable_devices_indices_map(shape)
-    cache: dict = {}
-    pieces = []
-    for dev, idx in imap.items():
-        key = _normalize_index(idx, shape) if idx else ()
-        if key not in cache:
-            cache[key] = np.ascontiguousarray(
-                read_region(path, key, manifest=m))
-        pieces.append(jax.device_put(cache[key], dev))
-    return jax.make_array_from_single_device_arrays(shape, sharding, pieces)
+        m = read_manifest(path)
+        return read_region(path, tuple((0, d) for d in m["shape"]),
+                           manifest=m)
+    return put_shards(read_shards(path, sharding), sharding)
 
 
 def stored_spec(path: str):

@@ -131,30 +131,39 @@ class ProjectionSource:
         leading projection axis); the whole array on one device if None.
         Encoded stores are decoded back to f32 (quantized data x scale
         sidecar) after the scatter read — each rank only ever reads and
-        dequantizes its own slice of the wire bytes."""
-        codec_name = self.codec_name
-        if mesh is None:
-            if codec_name is None:
-                return jax.device_put(shard_store.load_array(self.path))
-            data, scales = self.load_encoded()
-            return _jit_decode(codec_name)(
-                jnp.asarray(data),
-                None if scales is None else jnp.asarray(scales))
-        from jax.sharding import NamedSharding
-        from repro.core.distributed import _proj_spec, input_sharding
+        dequantizes its own slice of the wire bytes.
 
-        sharding = input_sharding(mesh)
-        data = shard_store.load_array(self.path, sharding)
-        if codec_name is None:
-            return data
-        scales = None
-        spath = os.path.join(self.path, SCALES_DIR)
-        if os.path.exists(os.path.join(spath, shard_store.MANIFEST)):
+        Traced as ``stage.read.copy`` (the store's bytes into host memory)
+        then ``stage.read.h2d`` (those bytes onto the devices, fenced); the
+        decode of an encoded store follows both."""
+        codec_name = self.codec_name
+        tracer = get_tracer()
+        if mesh is None:
+            with tracer.span("stage.read.copy"):
+                host = self.load_encoded()
+            with tracer.span("stage.read.h2d") as sp:
+                data, scales = sp.fence(jax.device_put(host))
+        else:
+            from jax.sharding import NamedSharding
+            from repro.core.distributed import _proj_spec, input_sharding
+
             # The sidecar is sharded along the projection axis exactly like
             # the data (one scale per projection): each rank scatter-reads
             # only its own slice, not the whole sidecar.
-            scales = shard_store.load_array(
-                spath, NamedSharding(mesh, _proj_spec(mesh)))
+            shardings = {self.path: input_sharding(mesh)}
+            spath = os.path.join(self.path, SCALES_DIR)
+            if os.path.exists(os.path.join(spath, shard_store.MANIFEST)):
+                shardings[spath] = NamedSharding(mesh, _proj_spec(mesh))
+            with tracer.span("stage.read.copy"):
+                host = {path: shard_store.read_shards(path, sharding)
+                        for path, sharding in shardings.items()}
+            with tracer.span("stage.read.h2d") as sp:
+                arrays = sp.fence(
+                    {path: shard_store.put_shards(host[path], sharding)
+                     for path, sharding in shardings.items()})
+            data, scales = arrays[self.path], arrays.get(spath)
+        if codec_name is None:
+            return data
         return _jit_decode(codec_name)(data, scales)
 
     # -- streaming discovery (the instant-CT source side) -------------------
@@ -318,10 +327,17 @@ class VolumeSink:
         chunked+scatter engine streams its internal 4-D
         (N_x, y_chunks, N_y/y_chunks, N_z) accumulator layout, recorded as
         ``{"kind": "y_chunk_major", "y_chunks": int}``. Without the record
-        a reader had no way to tell the store was not a plain volume."""
+        a reader had no way to tell the store was not a plain volume.
+
+        Traced as ``stage.write.d2h`` (the per-shard device_get) then
+        ``stage.write.file`` (the shard files and the manifest)."""
         extra = None if layout is None else {LAYOUT_KEY: layout}
-        return shard_store.save_array(self.path, volume,
-                                      extra_manifest=extra)
+        tracer = get_tracer()
+        with tracer.span("stage.write.d2h"):
+            host = shard_store.snapshot(volume)
+        with tracer.span("stage.write.file"):
+            return shard_store.save_array(self.path, host,
+                                          extra_manifest=extra)
 
     def layout(self) -> Optional[dict]:
         """The recorded engine layout, or None for a canonical store."""

@@ -37,6 +37,10 @@ Usage::
 Span names are dotted ``subsystem.event`` (e.g. ``stage.backproject``,
 ``service.bucket``, ``io.source.read``); the engine STAGE names consumed by
 `obs/attribution.py` are fixed vocabulary — see attribution.STAGE_FIELDS.
+The production I/O path splits its stages one level further:
+``stage.read.copy`` / ``stage.read.h2d`` inside ``stage.read`` and
+``stage.write.d2h`` / ``stage.write.file`` inside ``stage.write``
+(io/streams.py).
 """
 from __future__ import annotations
 
@@ -86,11 +90,12 @@ _NULL_SPAN = _NullSpan()
 
 class Span:
     """One named interval. Created by `Tracer.span` (context manager);
-    closed on context exit, after which `duration_s` / `dispatch_s` are
-    readable. Not reentrant — each `with` gets a fresh Span."""
+    closed on context exit, after which `duration_s` is readable. A span
+    that records is also a `jax.profiler.TraceAnnotation` of its name. Not
+    reentrant — each `with` gets a fresh Span."""
 
     __slots__ = ("name", "args", "_tracer", "_record", "_t0", "_t1",
-                 "_fence_ns", "_tid")
+                 "_fence_ns", "_tid", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, record: bool,
                  args: Optional[Dict[str, Any]]):
@@ -102,14 +107,21 @@ class Span:
         self._t1 = 0
         self._fence_ns: Optional[int] = None
         self._tid = 0
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         self._tid = threading.get_ident()
+        if self._record:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self._t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         if self._record:
@@ -133,11 +145,6 @@ class Span:
     @property
     def duration_s(self) -> float:
         return (self._t1 - self._t0) / 1e9
-
-    @property
-    def dispatch_s(self) -> Optional[float]:
-        """Elapsed at the fence point (None when the span never fenced)."""
-        return None if self._fence_ns is None else self._fence_ns / 1e9
 
 
 class Tracer:
@@ -188,23 +195,6 @@ class Tracer:
             args["dispatch_us"] = sp._fence_ns / 1e3
         if args:
             ev["args"] = args
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-                return
-            self._events.append(ev)
-
-    def instant(self, name: str, **attrs: Any) -> None:
-        """Zero-duration marker event (``ph: "i"``)."""
-        if not self.enabled:
-            return
-        ev = {
-            "ph": "i", "name": name, "s": "t",
-            "ts": (time.perf_counter_ns() - self._epoch_ns) / 1e3,
-            "pid": os.getpid(), "tid": threading.get_ident(),
-        }
-        if attrs:
-            ev["args"] = attrs
         with self._lock:
             if len(self._events) >= self.max_events:
                 self.dropped += 1

@@ -14,6 +14,8 @@ Nothing runs, so nothing here says anything about results or time.
 The topology is described inside a module fixture — never at import — so
 that only the test worker given this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -108,8 +110,15 @@ def test_one_chip_engine_fits(topo, compiled_kernel):
         g.proj_shape(), jnp.float32,
         sharding=SingleDeviceSharding(topo.devices[0]))
     compiled = engine.__wrapped__.lower(proj).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     assert _device_bytes(compiled) <= HBM_PER_DEVICE
+    # The kernel keeps its instruction name (the device trace finds it by
+    # it) under the back-projection stage's scope.
+    (scope,) = re.findall(
+        r'%backproject_dual[.\d]* = [^\n]*op_name="([^"]*)"', text)
+    assert "fdk.backproject" in scope.split("/")
+    assert re.search(r'op_name="[^"]*/fdk\.filter/', text)
 
 
 @pytest.mark.parametrize("reduce", ["psum", "scatter"])

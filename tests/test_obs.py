@@ -30,6 +30,29 @@ from repro.obs.trace import Span, Tracer
 from repro.parallel.mesh import make_mesh
 
 PERFETTO_KEYS = {"ph", "ts", "dur", "name", "pid", "tid"}
+# The production I/O path's sub-spans (io/streams.py), nested in their stage.
+IO_SPANS = {"stage.read.copy": "stage.read", "stage.read.h2d": "stage.read",
+            "stage.write.d2h": "stage.write",
+            "stage.write.file": "stage.write"}
+
+
+def _annotations(monkeypatch) -> list:
+    """Record every `jax.profiler.TraceAnnotation` entered and exited."""
+    log = []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return log
 
 
 @pytest.fixture
@@ -127,6 +150,37 @@ class TestSpans:
         tr.clear()
         assert tr.events() == [] and tr.dropped == 0
 
+    def test_recording_span_is_a_trace_annotation(self, tracer,
+                                                   monkeypatch):
+        log = _annotations(monkeypatch)
+        with tracer.span("unit.outer"):
+            with tracer.span("unit.inner"):
+                pass
+        assert log == [("enter", "unit.outer"), ("enter", "unit.inner"),
+                       ("exit", "unit.inner"), ("exit", "unit.outer")]
+
+    def test_disabled_path_has_no_event_annotation_or_fence(
+            self, monkeypatch, tmp_path):
+        """A disabled tracer, on the spans and on the instrumented I/O path:
+        no event, no annotation, no fence — `timed=True` included."""
+        log = _annotations(monkeypatch)
+        fences = []
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: fences.append(x) or x)
+        tr = Tracer(enabled=False)
+        prev = trace.set_tracer(tr)
+        try:
+            with tr.span("unit.a") as sp:
+                sp.fence(jnp.ones(2))
+            with tr.span("unit.b", timed=True):
+                pass
+            src = ProjectionSource.write(str(tmp_path / "p"),
+                                         np.ones((4, 2, 3), np.float32))
+            VolumeSink(str(tmp_path / "v")).write(src.load())
+        finally:
+            trace.set_tracer(prev)
+        assert tr.events() == [] and log == [] and fences == []
+
     def test_stage_totals_sums_per_name(self, tracer):
         for _ in range(3):
             with tracer.span("stage.fake"):
@@ -141,17 +195,13 @@ class TestPerfettoExport:
         with tracer.span("unit.a", k=1):
             with tracer.span("unit.b"):
                 pass
-        tracer.instant("unit.marker")
         out = tracer.export()
         json.loads(json.dumps(out))           # wire-format serializable
-        assert out["traceEvents"]
+        assert len(out["traceEvents"]) == 2
         for ev in out["traceEvents"]:
-            if ev["ph"] == "X":
-                assert PERFETTO_KEYS <= set(ev)
-                assert isinstance(ev["ts"], float) and ev["ts"] >= 0
-                assert isinstance(ev["dur"], float) and ev["dur"] >= 0
-            else:
-                assert ev["ph"] == "i" and "ts" in ev
+            assert ev["ph"] == "X" and PERFETTO_KEYS <= set(ev)
+            assert isinstance(ev["ts"], float) and ev["ts"] >= 0
+            assert isinstance(ev["dur"], float) and ev["dur"] >= 0
 
     def test_save_round_trips(self, tracer, tmp_path):
         with tracer.span("unit.saved"):
@@ -320,8 +370,9 @@ class TestAttribution:
     def test_every_engine_stage_measured(self, traced_run):
         _, _, _, _, _, tr, _ = traced_run
         measured = {e["name"] for e in tr.spans("stage.")}
-        assert measured == set(attribution.STAGE_FIELDS), (
-            "traced run must emit one span per engine stage")
+        assert measured == set(attribution.STAGE_FIELDS) | set(IO_SPANS), (
+            "traced run must emit one span per engine stage, and the read "
+            "and write split into their two parts")
         for name in attribution.STAGE_FIELDS:
             assert len([e for e in tr.spans(name)]) >= 1
 
@@ -376,6 +427,52 @@ class TestAttribution:
 # ---------------------------------------------------------------------------
 # instrumented subsystems
 # ---------------------------------------------------------------------------
+
+class TestIOSpans:
+    """The production path's read and write, each split into its two parts
+    (io/streams.py), nested in the stage span of core/plan.py."""
+
+    @pytest.mark.parametrize("codec", [None, "fp8_e4m3"])
+    @pytest.mark.parametrize("on_mesh", [False, True])
+    def test_read_and_write_split_inside_their_stage(self, tracer, tmp_path,
+                                                     codec, on_mesh):
+        g = default_geometry(16, n_proj=8)
+        proj = np.asarray(forward_project(g))
+        src = ProjectionSource.write(str(tmp_path / "proj"), proj,
+                                     codec=codec)
+        mesh = (make_mesh((1, 1, 1), ("pod", "data", "model"))
+                if on_mesh else None)
+        plan = plan_from_spec(g, "auto", mesh=mesh)
+        jax.block_until_ready(
+            plan.build(source=src, sink=VolumeSink(str(tmp_path / "v")))())
+        spans = {e["name"]: e for e in tracer.spans("stage.")}
+        assert set(spans) == {"stage.read", "stage.write"} | set(IO_SPANS)
+        for name, stage in IO_SPANS.items():
+            inner, outer = spans[name], spans[stage]
+            assert inner["tid"] == outer["tid"]
+            assert outer["ts"] <= inner["ts"]
+            assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+        for first, second in (("stage.read.copy", "stage.read.h2d"),
+                              ("stage.write.d2h", "stage.write.file")):
+            a, b = spans[first], spans[second]
+            assert a["ts"] + a["dur"] <= b["ts"]
+        assert "dispatch_us" in spans["stage.read.h2d"]["args"]
+
+    def test_split_write_stores_the_same_bytes(self, tmp_path):
+        """`VolumeSink.write` (snapshot, then save) lays down the files and
+        manifest that `save_array` of the device array does."""
+        from repro.io import shard_store
+
+        vol = jax.device_put(np.arange(4 * 6 * 8, dtype=np.float32)
+                             .reshape(4, 6, 8))
+        VolumeSink(str(tmp_path / "sink")).write(vol, layout={"kind": "x"})
+        shard_store.save_array(str(tmp_path / "direct"), vol,
+                               extra_manifest={"layout": {"kind": "x"}})
+        for rel in ("MANIFEST.json", "shards/shard_00000.bin"):
+            with open(tmp_path / "sink" / rel, "rb") as a, \
+                    open(tmp_path / "direct" / rel, "rb") as b:
+                assert a.read() == b.read(), rel
+
 
 class TestInstrumentation:
     def test_built_engine_emits_fenced_span(self, tracer):
