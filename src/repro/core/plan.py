@@ -31,12 +31,14 @@ operation of every schedule names its stage in its `op_name` metadata.
 
 Tuned Pallas block shapes for `impl="kernel"` are resolved ONCE at plan
 time (kernels/backproject/tune.py, file-backed cache) instead of per-call
-inside ops.py, and can be pinned explicitly via `blocks=(bi, bj, bs)`.
+inside ops.py, and can be pinned explicitly via `blocks=(bi, bj, bs)`. So
+is the kernel's u-step window K (`resolved_u_window`), from the scan's
+projection matrices at those blocks.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import lru_cache, partial
 from typing import Callable, Dict, Literal, NamedTuple, Optional, Tuple
 
 import jax
@@ -135,6 +137,14 @@ def bp_call_shape(g: CBCTGeometry, r: int, c: int, schedule: str,
                else g.n_y)
     np_call = g.n_proj // (c * n_steps)
     return nx_call, ny_call, np_call
+
+
+@lru_cache(maxsize=16)
+def _scan_matrices(g: CBCTGeometry) -> np.ndarray:
+    """projection_matrices(g), computed once per geometry (read-only)."""
+    pm = projection_matrices(g)
+    pm.setflags(write=False)
+    return pm
 
 
 def shift_pmats_j(pmats: Array, j0) -> Array:
@@ -346,7 +356,8 @@ class ReconstructionPlan:
     def resolved_blocks(self) -> Optional[Tuple[int, int, int]]:
         """The (bi, bj, bs) Pallas tile this plan will run with — explicit
         `blocks` if given, else the autotuner's pick for the per-call
-        back-projection shape. None for non-kernel impls."""
+        back-projection shape, ranked at each tile's u-window. None for
+        non-kernel impls."""
         if self.impl != "kernel":
             return None
         if self.blocks is not None:
@@ -358,7 +369,23 @@ class ReconstructionPlan:
         return tune.pick_blocks(nx_call, ny_call, g.n_z, np_call,
                                 g.n_u, g.n_v,
                                 qt_dtype=prec.storage_dtype,
-                                budget=self.vmem_budget)
+                                budget=self.vmem_budget,
+                                pmats=_scan_matrices(g),
+                                extent=(g.n_x, g.n_y))
+
+    def resolved_u_window(self) -> Optional[int]:
+        """The kernel's u-step window K at the resolved blocks: the widest
+        detector-column span any output tile of the whole volume touches in
+        any view (`tune.tile_window`), N_u when that reaches N_u. It holds
+        for every call of every schedule: x-slabs and y-chunks shift P by
+        whole tiles. None for non-kernel impls."""
+        if self.impl != "kernel":
+            return None
+        from repro.kernels.backproject import tune
+        g = self.geometry
+        bi, bj, _ = self.resolved_blocks()
+        return tune.tile_window(_scan_matrices(g), (g.n_x, g.n_y), bi, bj,
+                                g.n_u, self.resolved_precision().storage_dtype)
 
     def _resolve_backprojector(self) -> Callable:
         if self.impl != "kernel":
@@ -366,7 +393,17 @@ class ReconstructionPlan:
         from repro.kernels.backproject.ops import backproject_pallas
         bi, bj, bs = self.resolved_blocks()
         return partial(backproject_pallas, bi=bi, bj=bj, bs=bs,
-                       vmem_budget=self.vmem_budget)
+                       vmem_budget=self.vmem_budget,
+                       u_window=self.resolved_u_window())
+
+    def _window_attrs(self) -> dict:
+        """`u_window` (K) and `u_window_share` (K / N_u): how much of the
+        detector line the kernel's u-step contracts over. Empty for
+        non-kernel impls."""
+        kw = self.resolved_u_window()
+        if kw is None:
+            return {}
+        return {"u_window": kw, "u_window_share": kw / self.geometry.n_u}
 
     def _span_attrs(self) -> dict:
         """Fixed span args of this plan's engines (trace labels) — built
@@ -379,6 +416,7 @@ class ReconstructionPlan:
             "precision": self.resolved_precision().storage,
             "grid": f"{grid.r}x{grid.c}",
             "n_steps": self.n_steps,
+            **self._window_attrs(),
         }
 
     def describe(self) -> dict:
@@ -394,6 +432,7 @@ class ReconstructionPlan:
             "y_chunks": self.y_chunks,
             "reduce": self.reduce,
             "blocks": self.resolved_blocks(),
+            **self._window_attrs(),
         }
 
     # -- engine -------------------------------------------------------------

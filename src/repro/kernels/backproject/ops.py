@@ -27,7 +27,8 @@ def backproject_pallas(pmats: Array, proj: Array,
                        bs: int | None = None,
                        interpret: bool | None = None,
                        vmem_budget: int | None = None,
-                       scales: Array | None = None) -> Array:
+                       scales: Array | None = None,
+                       u_window: int | None = None) -> Array:
     """Alg. 4 via the Pallas kernel. Same signature/result as the oracles.
 
     pmats: (Np, 3, 4); proj: (Np, N_v, N_u) filtered projections (row = v),
@@ -42,6 +43,11 @@ def backproject_pallas(pmats: Array, proj: Array,
     `vmem_budget` (default REPRO_BP_VMEM_BUDGET), model-ranked — or timed
     once per (geometry, dtype) when REPRO_BP_AUTOTUNE=time. The kernel's
     scoped-VMEM limit is that budget (or the tile's working set, if larger).
+
+    `u_window` is the u-step's detector-column window K, derived on the
+    host from the scan's matrices at these blocks (`tune.tile_window`; the
+    engine passes it); None contracts over all N_u columns. The window
+    starts are computed here, per call, from `pmats`.
 
     `interpret` runs the Pallas interpreter; None defers to
     REPRO_PALLAS_INTERPRET (kernel.resolve_interpret).
@@ -65,10 +71,11 @@ def backproject_pallas(pmats: Array, proj: Array,
         qt = jnp.pad(qt, ((0, pad), (0, 0), (0, 0)))
         pm = jnp.pad(pm, ((0, pad), (0, 0)), constant_values=1.0)
     budget = tune.DEFAULT_VMEM_BUDGET if vmem_budget is None else vmem_budget
-    limit = max(budget, vmem_bytes(bi, bj, bs, nu, nv, nz // 2, qt.dtype))
+    limit = max(budget, vmem_bytes(bi, bj, bs, nu, nv, nz // 2, qt.dtype,
+                                   kw=u_window))
     dual = backproject_dual_pallas(
         pm, qt, nx, ny, nz, bi=bi, bj=bj, bs=bs, interpret=interpret,
-        vmem_limit=limit)
+        vmem_limit=limit, u_window=u_window)
     return from_dual_slab(dual)
 
 

@@ -6,7 +6,9 @@ reconstruction path to what the chip's compiler accepts, at the clinical
 scan `chip_smoke.py` runs (720 projections of 768x768 -> 512^3 f32):
 
   * the Pallas back-projection kernel lowers through Mosaic
-    (`tpu_custom_call` in the compiled module) at every wire dtype;
+    (`tpu_custom_call` in the compiled module) at every wire dtype, and
+    with its u-step window at the benchmark's RabbitCT scan (496 views of
+    1248x960 -> 512^3);
   * the one-chip engine and the 2x2 (data, model) mesh engines compile with
     the kernel inside and fit a v5e's 16 GiB of HBM per device.
 
@@ -14,6 +16,8 @@ Nothing runs, so nothing here says anything about results or time.
 The topology is described inside a module fixture — never at import — so
 that only the test worker given this file loads the TPU library.
 """
+import json
+import os
 import re
 
 import jax
@@ -23,11 +27,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.distributed import input_sharding
-from repro.core.geometry import default_geometry
+from repro.core.geometry import CBCTGeometry, default_geometry
 from repro.core.plan import ReconstructionPlan
 from repro.core.precision import resolve_precision
 from repro.kernels.backproject import tune
-from repro.kernels.backproject.kernel import backproject_dual_pallas
+from repro.kernels.backproject.kernel import (
+    backproject_dual_pallas, vmem_bytes,
+)
 from repro.parallel.mesh import make_mesh
 
 HBM_PER_DEVICE = 16 * 2**30  # TPU v5e
@@ -91,6 +97,38 @@ def _compile_kernel(topo, storage):
 @pytest.mark.parametrize("storage", ["fp32", "bf16", "fp8_e4m3", "fp8_e5m2"])
 def test_kernel_lowers_through_mosaic(topo, storage):
     compiled = _compile_kernel(topo, storage)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) <= HBM_PER_DEVICE
+
+
+@pytest.mark.parametrize("storage", ["fp32", "bf16", "fp8_e4m3"])
+def test_windowed_kernel_lowers_at_rabbitct(topo, storage):
+    """The plan's tile and u-window for the benchmark's RabbitCT scan, one
+    call over all 496 views: K is below N_u, the working set fits the
+    VMEM budget, and Mosaic compiles the kernel within that limit."""
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "configs",
+                        "rabbitct512.json")
+    with open(path) as f:
+        g = CBCTGeometry(**json.load(f)["geometry"])
+    plan = ReconstructionPlan(geometry=g, impl="kernel", precision=storage)
+    bi, bj, bs = plan.resolved_blocks()
+    kw = plan.resolved_u_window()
+    assert kw < g.n_u
+    dtype = resolve_precision(storage).storage_dtype
+    budget = tune.DEFAULT_VMEM_BUDGET
+    assert vmem_bytes(bi, bj, bs, g.n_u, g.n_v, g.n_z // 2, dtype,
+                      kw=kw) <= budget
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    pm = jax.ShapeDtypeStruct((g.n_proj, 13), jnp.float32, sharding=one_chip)
+    qt = jax.ShapeDtypeStruct((g.n_proj, g.n_u, g.n_v), dtype,
+                              sharding=one_chip)
+
+    def bp(pm, qt):
+        return backproject_dual_pallas(
+            pm, qt, g.n_x, g.n_y, g.n_z, bi=bi, bj=bj, bs=bs,
+            interpret=False, vmem_limit=budget, u_window=kw)
+
+    compiled = jax.jit(bp).lower(pm, qt).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) <= HBM_PER_DEVICE
 
