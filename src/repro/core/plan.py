@@ -405,10 +405,28 @@ class ReconstructionPlan:
             return {}
         return {"u_window": kw, "u_window_share": kw / self.geometry.n_u}
 
+    def _exchange_attrs(self) -> dict:
+        """What one rank's collectives move in one scan, at wire width, by
+        the planner's own accounting (planner/cost.py): `gather_bytes` the
+        column AllGather brings in, `reduce_bytes` the row reduce sends; 0
+        on one chip. `slab_shape`: the rank's partial slab."""
+        from repro.planner.cost import (
+            allgather_wire_bytes, point_from_plan, reduce_wire_bytes,
+        )
+        g = self.geometry
+        point = point_from_plan(self)
+        n_ranks = self.grid.n_ranks
+        return {
+            "gather_bytes": allgather_wire_bytes(g, point) // n_ranks,
+            "reduce_bytes": reduce_wire_bytes(g, point) // n_ranks,
+            "slab_shape": (g.n_x // self.grid.r, g.n_y, g.n_z),
+        }
+
     def _span_attrs(self) -> dict:
         """Fixed span args of this plan's engines (trace labels) — built
         once at build() time, JSON-plain for the Perfetto export."""
         grid = self.grid
+        exchange = self._exchange_attrs()
         return {
             "schedule": self.schedule,
             "impl": self.impl,
@@ -417,6 +435,8 @@ class ReconstructionPlan:
             "grid": f"{grid.r}x{grid.c}",
             "n_steps": self.n_steps,
             **self._window_attrs(),
+            **exchange,
+            "slab_shape": list(exchange["slab_shape"]),
         }
 
     def describe(self) -> dict:
@@ -433,6 +453,7 @@ class ReconstructionPlan:
             "reduce": self.reduce,
             "blocks": self.resolved_blocks(),
             **self._window_attrs(),
+            **self._exchange_attrs(),
         }
 
     # -- engine -------------------------------------------------------------

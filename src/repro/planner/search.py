@@ -12,7 +12,10 @@ The search space is the cross-product the plan layer exposes:
              one to a caller expecting `plan.build()` — and its figure of
              merit is latency (cost.time_from_last_delta), which the
              throughput ranking below does not capture.
-  reduce     psum | scatter | scatter_bf16 (half-width compensated scatter)
+  reduce     psum | scatter | scatter_bf16 (half-width compensated scatter).
+             scatter_bf16 rounds the partial slabs to bf16, so it is
+             enumerated beside fp32 storage only when the reduce is pinned:
+             a plan that promises fp32 answers keeps an f32-wire reduce.
   precision  fp32 | bf16 | fp16 | fp8_e4m3 | fp8_e5m2 (quarter-width +
              scale sidecar; e5m2 trades one mantissa bit for range)
   impl       factorized | kernel (| reference)
@@ -33,7 +36,9 @@ import dataclasses
 import math
 from typing import Iterable, Optional, Sequence
 
-from repro.core.distributed import IFDKGrid, SCATTER_REDUCES, grid_candidates
+from repro.core.distributed import (
+    IFDKGrid, REDUCE_WIRE_ITEMSIZE, SCATTER_REDUCES, grid_candidates,
+)
 from repro.core.geometry import CBCTGeometry
 from repro.core.perf_model import (
     ABCI, MachineSpec, PerfBreakdown, gups_end_to_end, machine_for,
@@ -48,6 +53,10 @@ _SCHEDULE_ORDER = ("fused", "pipelined", "chunked")
 # Ranking knows every schedule, including the pin-only streaming one.
 _RANK_SCHEDULE_ORDER = _SCHEDULE_ORDER + ("incremental",)
 _REDUCE_ORDER = ("psum", "scatter", "scatter_bf16")
+# The reduces that move the partial slabs at f32 width: all an unpinned
+# search offers fp32 storage.
+_F32_WIRE_REDUCES = tuple(r for r in _REDUCE_ORDER
+                          if REDUCE_WIRE_ITEMSIZE[r] == 4)
 # Tie-break order within equal wire width: e4m3 before e5m2 (one extra
 # mantissa bit ~= 6 dB PSNR at the same bytes; e5m2 wins only when pinned
 # for its exponent range).
@@ -117,7 +126,7 @@ def _rank_key(p: PlanProposal):
 
 def enumerate_points(g: CBCTGeometry, grid: IFDKGrid, *,
                      schedules: Sequence[str] = _SCHEDULE_ORDER,
-                     reduces: Sequence[str] = _REDUCE_ORDER,
+                     reduces: Sequence[str] | None = None,
                      precisions: Sequence[str] = _PRECISION_ORDER,
                      impls: Sequence[str] = ("factorized", "kernel"),
                      n_steps_candidates: Sequence[int] = DEFAULT_N_STEPS,
@@ -125,7 +134,9 @@ def enumerate_points(g: CBCTGeometry, grid: IFDKGrid, *,
                      data_size: int | None = None,
                      ) -> Iterable[PlanPoint]:
     """All divisibility-valid plan points on one grid. `data_size` stamps
-    the mesh's `data` axis extent onto the points (see PlanPoint)."""
+    the mesh's `data` axis extent onto the points (see PlanPoint).
+    `reduces` None is every reduce, less the half-width one beside fp32
+    storage; a sequence given is enumerated as it is (a pin)."""
     if g.n_proj % grid.n_ranks or g.n_x % grid.r:
         return
     np_local = g.n_proj // grid.n_ranks
@@ -136,10 +147,15 @@ def enumerate_points(g: CBCTGeometry, grid: IFDKGrid, *,
                       [y for y in y_chunks_candidates if g.n_y % y == 0])
         for n_steps in steps:
             for y_chunks in chunk_opts:
-                for reduce in reduces:
+                for reduce in _REDUCE_ORDER if reduces is None else reduces:
                     if reduce in SCATTER_REDUCES and grid.c == 1:
                         continue  # nothing to scatter over
                     for precision in precisions:
+                        if (reduces is None
+                                and reduce not in _F32_WIRE_REDUCES
+                                and resolve_precision(precision).storage
+                                == "fp32"):
+                            continue
                         for impl in impls:
                             if impl == "kernel" and g.n_z % 2:
                                 continue

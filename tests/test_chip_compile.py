@@ -10,7 +10,10 @@ scan `chip_smoke.py` runs (720 projections of 768x768 -> 512^3 f32):
     with its u-step window at the benchmark's RabbitCT scan (496 views of
     1248x960 -> 512^3);
   * the one-chip engine and the 2x2 (data, model) mesh engines compile with
-    the kernel inside and fit a v5e's 16 GiB of HBM per device.
+    the kernel inside and fit a v5e's 16 GiB of HBM per device;
+  * the benchmark's `rabbitct1024` deployment (496 views of 1248x960 ->
+    1024^3 on the 2x2 mesh), with the plan the planner picks for it, does
+    too, and its row reduce moves the partial slabs at f32.
 
 Nothing runs, so nothing here says anything about results or time.
 The topology is described inside a module fixture — never at import — so
@@ -70,6 +73,13 @@ def _geometry():
     return default_geometry(N, n_proj=N_PROJ)
 
 
+def _bench_config(name: str) -> dict:
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "configs",
+                        f"{name}.json")
+    with open(path) as f:
+        return json.load(f)
+
+
 def _device_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -106,10 +116,7 @@ def test_windowed_kernel_lowers_at_rabbitct(topo, storage):
     """The plan's tile and u-window for the benchmark's RabbitCT scan, one
     call over all 496 views: K is below N_u, the working set fits the
     VMEM budget, and Mosaic compiles the kernel within that limit."""
-    path = os.path.join(os.path.dirname(__file__), "..", "bench", "configs",
-                        "rabbitct512.json")
-    with open(path) as f:
-        g = CBCTGeometry(**json.load(f)["geometry"])
+    g = CBCTGeometry(**_bench_config("rabbitct512")["geometry"])
     plan = ReconstructionPlan(geometry=g, impl="kernel", precision=storage)
     bi, bj, bs = plan.resolved_blocks()
     kw = plan.resolved_u_window()
@@ -196,3 +203,41 @@ def test_planner_pick_fits_with_its_service_bucket(topo, compiled_kernel):
     compiled = engine.__wrapped__.lower(proj).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == (best.impl == "kernel")
     assert _device_bytes(compiled) <= hbm
+
+
+def test_rabbitct1024_mesh_engine_fits_with_an_f32_reduce(topo,
+                                                          compiled_kernel):
+    """The planner's pick for `rabbitct1024` under its pins (fp32, kernel,
+    the reduce left to the planner) on a v5e:2x2: the kernel lowers, each
+    device's program fits HBM, the column AllGather and the row reduce sit
+    under their stage scopes, and the reduce stays f32 end to end: no bf16
+    value anywhere under `fdk.reduce`."""
+    from repro.core.perf_model import TPU_V5E
+    from repro.planner import auto_plan
+
+    config = _bench_config("rabbitct1024")
+    g = CBCTGeometry(**config["geometry"])
+    mesh = make_mesh(tuple(config["mesh"].values()), tuple(config["mesh"]),
+                     devices=np.asarray(topo.devices))
+    plan = auto_plan(g, mesh=mesh, system=TPU_V5E,
+                     hbm_bytes=int(15.75 * 2**30), calibration=None,
+                     **config["plan"])
+    assert plan.reduce in ("psum", "scatter")
+    assert plan.describe()["slab_shape"] == (g.n_x // 2, g.n_y, g.n_z)
+    proj = jax.ShapeDtypeStruct(g.proj_shape(), jnp.float32,
+                                sharding=input_sharding(mesh))
+    compiled = plan.build().__wrapped__.lower(proj).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%backproject_dual[.\d]* = ", text)
+    assert _device_bytes(compiled) <= HBM_PER_DEVICE
+    lines = [line for line in text.splitlines() if " = " in line]
+    gathers = [line for line in lines if " all-gather(" in line]
+    reduces = [line for line in lines
+               if re.search(r" (reduce-scatter|all-reduce)\(", line)]
+    assert gathers and all("/fdk.gather/" in line for line in gathers)
+    assert reduces and all("/fdk.reduce/" in line for line in reduces)
+    assert all(line.split(" = ", 1)[1].startswith("f32[")
+               for line in reduces)
+    assert not [line for line in lines
+                if "/fdk.reduce/" in line and "bf16[" in line]

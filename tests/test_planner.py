@@ -438,6 +438,67 @@ class TestStreamCodecPlanner:
         assert all(p.point.reduce == "scatter_bf16" for p in props)
 
 
+class TestFp32KeepsAnF32Reduce:
+    """Half-width wire bytes price the scatter_bf16 row reduce cheapest, so
+    an unpinned search used to hand fp32 storage a lossy bf16 reduce. Under
+    fp32 storage an unpinned search now offers only the reduces that move
+    the partial slabs at f32; bf16 storage may still take scatter_bf16,
+    and a pinned reduce is searched as given."""
+
+    ALL_REDUCES = ("psum", "scatter", "scatter_bf16")
+
+    def test_unpinned_fp32_never_yields_scatter_bf16(self):
+        g = paper_problem()
+        pts = list(enumerate_points(g, GRID_256))
+        assert {p.reduce for p in pts if p.precision == "fp32"} == {
+            "psum", "scatter"}
+        ranked = search_grids(g, 256, precisions=("fp32",), top_k=None)
+        assert ranked
+        assert all(p.point.reduce != "scatter_bf16" for p in ranked)
+        assert ranked[0].point.reduce == "scatter"
+
+    def test_offered_every_reduce_fp32_would_rank_scatter_bf16_first(self):
+        """Why the rule lives in the enumeration: the cost model alone
+        ranks the lossy reduce above the exact one."""
+        best = search_grids(paper_problem(), 256, precisions=("fp32",),
+                            reduces=self.ALL_REDUCES, top_k=1)[0]
+        assert best.point.reduce == "scatter_bf16"
+
+    @pytest.mark.parametrize("precision", ["bf16", "fp8_e4m3"])
+    def test_narrower_storage_may_take_scatter_bf16(self, precision):
+        g = paper_problem()
+        pts = list(enumerate_points(g, GRID_256, precisions=(precision,)))
+        assert {p.reduce for p in pts} == set(self.ALL_REDUCES)
+        best = search_grids(g, 256, precisions=(precision,), top_k=1)[0]
+        assert best.point.reduce == "scatter_bf16"
+
+    def test_a_pinned_reduce_wins_under_fp32(self):
+        g = paper_problem()
+        pinned = search_grids(g, 256, precisions=("fp32",),
+                              reduces=("scatter_bf16",), top_k=None)
+        assert pinned
+        assert {p.point.reduce for p in pinned} == {"scatter_bf16"}
+        assert {p.point.precision for p in pinned} == {"fp32"}
+
+    def test_auto_plan_pin_reaches_the_search(self, monkeypatch):
+        """auto_plan hands a `reduce` pin to the search as the only reduce,
+        and leaves the reduces to the enumeration's rule otherwise."""
+        from repro.planner import search as search_mod
+        seen = []
+
+        def spy(g, mesh=None, **kw):
+            seen.append(kw.get("reduces"))
+            raise ValueError("stop")
+
+        monkeypatch.setattr(search_mod, "search_plans", spy)
+        g = default_geometry(16, n_proj=8)
+        for pins in ({"precision": "fp32"},
+                     {"precision": "fp32", "reduce": "scatter_bf16"}):
+            with pytest.raises(ValueError, match="stop"):
+                auto_plan(g, calibration=None, **pins)
+        assert seen == [None, ("scatter_bf16",)]
+
+
 # ---------------------------------------------------------------------------
 # auto_plan / plan_from_spec("auto") wiring
 # ---------------------------------------------------------------------------
